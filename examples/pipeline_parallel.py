@@ -59,22 +59,23 @@ def main() -> None:
 
     # --- step 3: execute on the mesh (shard_map + ppermute) ----------------
     n_dev = len(jax.devices())
-    if n_dev >= args.stages:
-        mesh = make_pipeline_mesh(args.stages, 1, 1)
-        params = tf.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-        sparams = stack_stage_params(cfg, params, plan)
-        fn = jax.jit(make_pipeline_forward(cfg, plan, mesh))
-        toks = jax.random.randint(jax.random.PRNGKey(1), (args.microbatches, mb, S),
-                                  0, cfg.vocab_size)
-        out = fn(sparams, toks)
-        ref, _ = tf.forward(cfg, params, {"tokens": toks.reshape(B, S)})
-        err = float(jnp.max(jnp.abs(out.reshape(B, S, -1) - ref)))
-        print(f"\nmesh execution: logits {out.shape}, max |delta| vs plain "
-              f"forward = {err:.2e}")
-    else:
-        print(f"\n({n_dev} device(s): set XLA_FLAGS="
-              f"--xla_force_host_platform_device_count={args.stages} "
-              f"to run the mesh execution step)")
+    if n_dev < args.stages:
+        raise SystemExit(
+            f"{n_dev} device(s) for {args.stages} stages: set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={args.stages} "
+            f"to run the mesh execution step on the CPU"
+        )
+    mesh = make_pipeline_mesh(args.stages, 1, 1)
+    params = tf.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    sparams = stack_stage_params(cfg, params, plan)
+    fn = jax.jit(make_pipeline_forward(cfg, plan, mesh))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (args.microbatches, mb, S),
+                              0, cfg.vocab_size)
+    out = fn(sparams, toks)
+    ref, _ = tf.forward(cfg, params, {"tokens": toks.reshape(B, S)})
+    err = float(jnp.max(jnp.abs(out.reshape(B, S, -1) - ref)))
+    print(f"\nmesh execution: logits {out.shape}, max |delta| vs plain "
+          f"forward = {err:.2e}")
 
     # --- step 4: strategy switching without reconfiguration ----------------
     # 4a. On the simulator: the PU array is fixed; sim.reset() clears only

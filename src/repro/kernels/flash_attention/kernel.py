@@ -3,8 +3,11 @@ sliding-window.
 
 Tiling: grid = (batch, q_heads, q_blocks, kv_blocks); the kv dimension is
 "arbitrary" (sequential) so the VMEM scratch accumulators (m, l, acc) carry
-across kv blocks. Block shapes default to (128, head_dim) — MXU-aligned on
-the 128 lane dimension; the (Bq, Bk) score tile hits the 128x128 MXU.
+across kv blocks. The kernel runs head-major: the wrapper transposes
+(b, s, H, hd) to (b, H, s, hd) so that every block ends in (rows, head_dim),
+which the TPU's (8, 128) tiling accepts (a head axis of block 1 second from
+last does not). Block shapes default to (128, head_dim) — MXU-aligned on the
+128 lane dimension; the (Bq, Bk) score tile hits the 128x128 MXU.
 
 HBM->VMEM movement per (q_block): q once, k/v streamed per kv block — the
 same URAM/BRAM streaming discipline as the paper's PU, re-derived for the
@@ -41,9 +44,9 @@ def _attn_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (Bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (Bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)  # (Bq, hd)
+    k = k_ref[0, 0].astype(jnp.float32)  # (Bk, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     # zero padded kv rows: ragged final blocks are padded out-of-bounds and
     # 0 * pad_garbage would still poison the p @ v matmul.
@@ -77,7 +80,7 @@ def _attn_kernel(
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[0, :, 0, :] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -111,17 +114,14 @@ def flash_attention_tpu(
         scale=sc, causal=causal, window=window,
         block_q=bq, block_k=bk, kv_len=t,
     )
-    grid = (b, H, nq, nk)
+    q_spec = pl.BlockSpec((1, 1, bq, hd), lambda bb, h, qi, ki: (bb, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, hd), lambda bb, h, qi, ki: (bb, h // rep, ki, 0))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda bb, h, qi, ki: (bb, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda bb, h, qi, ki, _rep=rep: (bb, ki, h // _rep, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda bb, h, qi, ki, _rep=rep: (bb, ki, h // _rep, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd), lambda bb, h, qi, ki: (bb, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, H, hd), q.dtype),
+        grid=(b, H, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, H, s, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -131,5 +131,5 @@ def flash_attention_tpu(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v)
-    return out
+    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

@@ -1,11 +1,9 @@
 """Dispatch wrapper: Pallas kernel on TPU, jnp reference elsewhere.
 
-``REPRO_FORCE_REF=1`` forces the reference path (used to validate the
-dispatcher itself); tests exercise the kernel explicitly via interpret=True.
+Tests exercise the kernel explicitly via interpret=True.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -19,8 +17,6 @@ CHUNKED_THRESHOLD = 2048
 
 
 def _use_kernel() -> bool:
-    if os.environ.get("REPRO_FORCE_REF"):
-        return False
     return jax.default_backend() == "tpu"
 
 

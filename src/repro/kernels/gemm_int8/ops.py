@@ -1,8 +1,6 @@
 """Dispatch wrapper for the INT8 PU GEMM."""
 from __future__ import annotations
 
-import os
-
 import jax
 
 from .kernel import gemm_int8_tpu
@@ -10,8 +8,6 @@ from .ref import gemm_int8_reference
 
 
 def _use_kernel() -> bool:
-    if os.environ.get("REPRO_FORCE_REF"):
-        return False
     return jax.default_backend() == "tpu"
 
 

@@ -1,8 +1,6 @@
 """Dispatch wrapper for the wkv6 recurrence."""
 from __future__ import annotations
 
-import os
-
 import jax
 
 from .kernel import wkv6_tpu
@@ -10,16 +8,12 @@ from .ref import wkv6_chunked, wkv6_reference
 
 
 def _use_kernel() -> bool:
-    if os.environ.get("REPRO_FORCE_REF"):
-        return False
     return jax.default_backend() == "tpu"
 
 
 def wkv6(r, k, v, w, u, state):
     if _use_kernel():
         return wkv6_tpu(r, k, v, w, u, state)
-    if os.environ.get("REPRO_FORCE_REF"):
-        return wkv6_reference(r, k, v, w, u, state)
     if r.shape[1] > 1:
         # chunked parallel form: seq/chunk state hops instead of a
         # seq-length sequential scan (exact up to fp reassociation)

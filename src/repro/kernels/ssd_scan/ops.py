@@ -1,8 +1,6 @@
 """Dispatch wrapper for the SSD scan."""
 from __future__ import annotations
 
-import os
-
 import jax
 
 from .kernel import ssd_scan_tpu
@@ -10,8 +8,6 @@ from .ref import ssd_reference
 
 
 def _use_kernel() -> bool:
-    if os.environ.get("REPRO_FORCE_REF"):
-        return False
     return jax.default_backend() == "tpu"
 
 

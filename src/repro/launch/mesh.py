@@ -4,18 +4,26 @@ Single pod: 16 x 16 = 256 chips, axes ("data", "model").
 Multi-pod:  2 x 16 x 16 = 512 chips, axes ("pod", "data", "model") — the
 "pod" axis is pure data parallelism across pods (DCN-connected).
 
+Meshes use Auto axes: the sharding policy places activations with
+``with_sharding_constraint``, which explicit-axis meshes reject.
+
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int | None = None):
@@ -26,4 +34,4 @@ def make_debug_mesh(n_devices: int | None = None):
         if n % cand == 0:
             model = cand
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))

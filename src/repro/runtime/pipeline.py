@@ -24,22 +24,8 @@ from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax keeps it under experimental
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-# The replication-check kwarg was renamed check_rep -> check_vma across jax
-# versions; pass whichever this jax understands.
-_SHMAP_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from ..configs.base import ArchConfig
 from ..core.isa import Compute, Group, Opcode, Sync
@@ -180,7 +166,10 @@ def emit_stage_programs(plan: PipelinePlan) -> list[PUProgram]:
 
 # ---------------------------------------------------------------- executor --
 def make_pipeline_mesh(n_stages: int, n_data: int = 1, n_model: int = 1):
-    return jax.make_mesh((n_stages, n_data, n_model), ("stage", "data", "model"))
+    # Auto axes: the executor slices the last stage's logits out of a
+    # stage-sharded array, which explicit-axis sharding rejects
+    return jax.make_mesh((n_stages, n_data, n_model), ("stage", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
 
 
 def stack_stage_params(cfg: ArchConfig, params: dict, plan: PipelinePlan) -> dict:
@@ -284,7 +273,7 @@ def make_pipeline_forward(cfg: ArchConfig, plan: PipelinePlan, mesh: Mesh):
             mesh=mesh,
             in_specs=(pspec_params, P()),
             out_specs=P("stage"),
-            **_SHMAP_NOCHECK,
+            check_vma=False,
         )(params, tokens)
         # logits live on the last stage; slice it out
         return out[-1]

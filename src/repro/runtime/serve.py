@@ -57,6 +57,9 @@ class Request:
     prompt: list[int]
     max_new_tokens: int
     generated: list[int] = field(default_factory=list)
+    # next-token logits after the last prompt token (the decode path's
+    # prefill result); the first generated token is their argmax
+    prompt_logits: Optional[jax.Array] = None
     done: bool = False
     submitted_at: float = 0.0
     finished_at: float = 0.0
@@ -106,9 +109,11 @@ class ServingEngine:
                 # prefill token-by-token into this slot's cache lane (simple
                 # and uniform across SSM/attention families)
                 for t in req.prompt:
-                    self._step_slot(i, t)
+                    req.prompt_logits = self._step_slot(i, t)
 
-    def _step_slot(self, i: int, token: int) -> int:
+    def _step_slot(self, i: int, token: int) -> jax.Array:
+        """Feed ``token`` to slot ``i`` at its position; returns that slot's
+        next-token logits (vocab,)."""
         batch = {"tokens": jnp.full((len(self.slots), 1), token, jnp.int32)}
         logits, caches = self._decode(
             self.params, self.caches, batch, jnp.int32(self.pos[i])
@@ -126,16 +131,21 @@ class ServingEngine:
             caches,
         )
         self.pos[i] += 1
-        return int(jnp.argmax(logits[i, -1]))
+        return logits[i, -1]
 
     def step(self) -> None:
-        """One engine tick: admit + one decode step for every active slot."""
+        """One engine tick: admit + one token for every active slot (the
+        first token comes from the prompt's logits, later ones from a decode
+        step on the previous token)."""
         self._admit()
         for i, req in enumerate(self.slots):
             if req is None:
                 continue
-            last = req.generated[-1] if req.generated else req.prompt[-1]
-            nxt = self._step_slot(i, last)
+            if req.generated:
+                logits = self._step_slot(i, req.generated[-1])
+            else:
+                logits = req.prompt_logits
+            nxt = int(jnp.argmax(logits))
             req.generated.append(nxt)
             if len(req.generated) >= req.max_new_tokens or (
                 self.eos is not None and nxt == self.eos

@@ -32,22 +32,6 @@ from ..configs.base import ArchConfig
 FSDP_THRESHOLD_BYTES = 4 << 30  # per-chip bf16 param budget before FSDP
 
 
-def make_abstract_mesh(shape: tuple[int, ...], axis_names: tuple[str, ...]):
-    """Device-free mesh carrying only (name, size) metadata.
-
-    Policy construction (``make_policy``) only reads mesh *shape* metadata, so
-    tests and planners can use an AbstractMesh without real devices. jax
-    changed the AbstractMesh constructor from ``(shape, axis_names)`` to a
-    single ``shape_tuple`` of (name, size) pairs (>= 0.4.36); this helper
-    speaks whichever form the installed jax expects."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
-
-
 def _path_str(path) -> str:
     parts = []
     for k in path:

@@ -236,6 +236,25 @@ class TestServingEngine:
             outs.append(tuple(eng.run_until_drained()[0].generated))
         assert outs[0] == outs[1]
 
+    def test_generation_matches_forward(self):
+        """The decode path's logits after the prompt equal the full-sequence
+        forward's, and each generated token is the forward's greedy choice
+        (the prompt's last token is fed once, not again as the first input)."""
+        from repro.runtime.serve import ServingEngine
+
+        cfg = get_config("qwen3-0.6b").reduced()
+        params = tf.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+        prompt = [5, 6, 7, 8]
+        eng = ServingEngine(cfg, params, batch_slots=2, max_len=32)
+        eng.submit(prompt, max_new_tokens=3)
+        (req,) = eng.run_until_drained()
+        ref, _ = tf.forward(cfg, params, {"tokens": jnp.array([prompt])})
+        np.testing.assert_allclose(req.prompt_logits, ref[0, -1], rtol=1e-4, atol=1e-4)
+        for n in range(3):
+            seq = jnp.array([prompt + req.generated[:n]])
+            logits, _ = tf.forward(cfg, params, {"tokens": seq})
+            assert req.generated[n] == int(jnp.argmax(logits[0, -1]))
+
 
 # ------------------------------------------------------------------ pipeline --
 class TestPipelinePlanner:
@@ -311,6 +330,7 @@ def test_pipeline_forward_matches_reference_subprocess():
     ppermute pipeline must reproduce the plain forward logits."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # the child never reaches for an accelerator
     out = subprocess.run(
         [sys.executable, "-c", MULTIDEV_SCRIPT],
         capture_output=True, text=True, env=env, timeout=600,

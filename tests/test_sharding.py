@@ -14,12 +14,14 @@ from repro.configs import all_configs, get_config
 class TestPolicyRules:
     def _policy(self, arch, multi_pod=False):
         # policy construction only needs mesh *shape* metadata; build a
-        # device-free mesh via the version-robust helper
-        from repro.runtime.sharding import make_abstract_mesh, make_policy
+        # device-free mesh
+        from jax.sharding import AbstractMesh
+
+        from repro.runtime.sharding import make_policy
 
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        mesh = make_abstract_mesh(shape, axes)
+        mesh = AbstractMesh(shape, axes)
         return make_policy(get_config(arch), mesh)
 
     def test_attn_mode_by_divisibility(self):
@@ -88,10 +90,12 @@ import jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.configs.base import ShapeCfg
 from repro.launch import specs
+from repro.launch.mesh import make_debug_mesh
 from repro.runtime.sharding import make_policy
 from repro.runtime.serve import make_serve_step, make_prefill
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(8)  # (2, 4) over ("data", "model")
+assert dict(mesh.shape) == {"data": 2, "model": 4}, mesh.shape
 arch = os.environ["TEST_ARCH"]
 cfg = get_config(arch).reduced()
 policy = make_policy(cfg, mesh)
@@ -119,6 +123,7 @@ def test_reduced_configs_compile_on_small_mesh(arch):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     env["TEST_ARCH"] = arch
+    env["JAX_PLATFORMS"] = "cpu"  # the child never reaches for an accelerator
     out = subprocess.run(
         [sys.executable, "-c", SMALL_MESH_SCRIPT],
         capture_output=True, text=True, env=env, timeout=900,
