@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from ..runtime.pspec import constrain
+from ..runtime.pspec import constrain, shards
 from .layers import apply_rope, normal, rmsnorm
 
 
@@ -75,6 +75,66 @@ def init_kv_cache(cfg: ArchConfig, n_layers: int, batch: int, length: int, dtype
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+DECODE_BLOCK = 128  # cache rows a decode step reads at a time
+
+
+def decode_rows(pos: int, cache_len: int) -> int:
+    """Cache rows a decode at ``pos`` reads: the ``DECODE_BLOCK``-row blocks
+    that hold the live slots ``0..min(pos, cache_len - 1)`` (a ring buffer
+    fills them in order before it wraps, and after the wrap every slot is
+    live), the last block ending at the cache's end."""
+    blk = min(DECODE_BLOCK, cache_len)
+    live = min(pos, cache_len - 1) + 1
+    return min(cache_len, -(-live // blk) * blk)
+
+
+def _attend_all(q, ck, cv, pos_c):
+    """q (b, G, H/G, hd) over every row of the cache (b, S, G, hd), rows past
+    ``pos_c`` masked: each cached K/V head is read once for its group of
+    query heads."""
+    # preferred_element_type keeps the cache operand bf16 (an .astype(f32)
+    # on the output makes XLA materialize an f32 copy of the cache)
+    scores = jnp.einsum("bgrq,btgq->bgrt", q, ck, preferred_element_type=jnp.float32)
+    scores = constrain(scores, "decode_scores")  # t-sharded (flash-decoding)
+    scores *= 1.0 / math.sqrt(q.shape[-1])
+    scores = jnp.where(jnp.arange(ck.shape[1]) <= pos_c, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
+    out = jnp.einsum("bgrt,btgq->bgrq", probs, cv, preferred_element_type=jnp.float32)
+    return out.astype(cv.dtype)
+
+
+def _attend_live_blocks(q, ck, cv, pos_c):
+    """``_attend_all`` over only the blocks that hold rows ``0..pos_c``: an
+    online softmax over ``DECODE_BLOCK``-row blocks, as many as ``pos_c``
+    needs (flash-decoding); one block when the cache holds no more. The last
+    block of a cache whose length is not a multiple of the block ends at the
+    cache's end and masks the rows an earlier block read."""
+    b, G, R, hd = q.shape
+    cache_len = ck.shape[1]
+    blk = min(DECODE_BLOCK, cache_len)
+    scale = 1.0 / math.sqrt(hd)
+
+    def block(i, carry):
+        m, l, acc = carry
+        start = jnp.minimum(i * blk, cache_len - blk)
+        kb = jax.lax.dynamic_slice_in_dim(ck, start, blk, axis=1)
+        vb = jax.lax.dynamic_slice_in_dim(cv, start, blk, axis=1)
+        s = jnp.einsum("bgrq,btgq->bgrt", q, kb, preferred_element_type=jnp.float32) * scale
+        t = start + jnp.arange(blk)
+        s = jnp.where((t >= i * blk) & (t <= pos_c), s, -1e30)
+        m_new = jnp.maximum(m, s.max(-1))
+        corr = jnp.exp(m - m_new)
+        e = jnp.exp(s - m_new[..., None])
+        pv = jnp.einsum("bgrt,btgq->bgrq", e.astype(vb.dtype), vb,
+                        preferred_element_type=jnp.float32)
+        return m_new, l * corr + e.sum(-1), acc * corr[..., None] + pv
+
+    init = (jnp.full((b, G, R), -1e30, jnp.float32), jnp.zeros((b, G, R), jnp.float32),
+            jnp.zeros((b, G, R, hd), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, pos_c // blk + 1, block, init)
+    return (acc / l[..., None]).astype(cv.dtype)
+
+
 def decode_attention(
     p: dict,
     cfg: ArchConfig,
@@ -84,6 +144,14 @@ def decode_attention(
     *,
     local: bool,
 ) -> tuple[jax.Array, dict]:
+    """One token's attention over the layer's cache, after writing its K/V
+    row. Reads only the ``decode_rows(pos, S)`` rows that hold live slots,
+    in a loop whose trip count the device takes from the traced ``pos`` (one
+    program for every position); the rows past them would add exactly 0
+    after the softmax. Under an activation policy that shards
+    ``decode_scores`` (over the cache length: flash-decoding across
+    devices), a block slice would reshard it, so the whole cache is read
+    there."""
     b = x.shape[0]
     H, G, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     cache_len = layer_cache["k"].shape[1]
@@ -91,23 +159,15 @@ def decode_attention(
     q, k, v = _project_qkv(p, cfg, x, positions)  # q:(b,1,H,hd) k/v:(b,1,G,hd)
 
     # ring-buffer slot for windowed layers; plain slot otherwise
-    slot = jnp.where(jnp.array(local), pos % cache_len, jnp.minimum(pos, cache_len - 1))
+    pos_c = jnp.minimum(pos, cache_len - 1)
+    slot = jnp.where(jnp.array(local), pos % cache_len, pos_c)
     ck = jax.lax.dynamic_update_slice(layer_cache["k"], k, (0, slot, 0, 0))
     cv = jax.lax.dynamic_update_slice(layer_cache["v"], v, (0, slot, 0, 0))
 
-    from ..kernels.flash_attention.ref import repeat_kv
-
-    kr = repeat_kv(ck, H // G)  # (b, t, H, hd); broadcast fuses, no copy
-    vr = repeat_kv(cv, H // G)
-    # preferred_element_type keeps the cache operand bf16 (an .astype(f32)
-    # on the output makes XLA materialize an f32 copy of the whole cache)
-    scores = jnp.einsum("buhq,bthq->bhut", q, kr,
-                        preferred_element_type=jnp.float32)
-    scores = constrain(scores, "decode_scores")  # t-sharded (flash-decoding)
-    scores *= 1.0 / math.sqrt(hd)
-    valid = jnp.arange(cache_len)[None, :] <= jnp.minimum(pos, cache_len - 1)
-    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
-    out = jnp.einsum("bhut,bthq->buhq", probs, vr)
-    y = jnp.einsum("bshq,hqd->bsd", out, p["wo"])
+    qg = q.reshape(b, G, H // G, hd)  # query head h = g * (H/G) + r
+    if shards("decode_scores", (b, G, H // G, cache_len)):
+        out = _attend_all(qg, ck, cv, pos_c)
+    else:
+        out = _attend_live_blocks(qg, ck, cv, pos_c)
+    y = jnp.einsum("bshq,hqd->bsd", out.reshape(b, 1, H, hd), p["wo"])
     return y, {"k": ck, "v": cv}

@@ -29,28 +29,37 @@ def activation_policy(mesh, specs: dict[str, P]):
         _POLICY.reset(tok)
 
 
+def _axis_size(mesh, ax) -> int:
+    n = 1
+    for a in ax if isinstance(ax, tuple) else (ax,):
+        n *= mesh.shape[a]
+    return n
+
+
+def _fitted_spec(pol: dict, name: str, shape) -> Optional[P]:
+    """The policy's spec for ``name`` on an array of ``shape``, with the mesh
+    axes that do not divide their dimension dropped (e.g. seq-parallel specs
+    against a decode step's length-1 sequence axis); None without a spec."""
+    spec = pol["specs"].get(name)
+    if spec is None or len(spec) > len(shape):
+        return None
+    mesh = pol["mesh"]
+    return P(*(ax if ax is not None and dim % _axis_size(mesh, ax) == 0 else None
+               for dim, ax in zip(shape, spec)))
+
+
 def constrain(x, name: str):
     pol = _POLICY.get()
-    if pol is None:
+    spec = None if pol is None else _fitted_spec(pol, name, x.shape)
+    if spec is None:
         return x
-    spec = pol["specs"].get(name)
-    if spec is None or len(spec) > x.ndim:
-        return x
-    # drop mesh axes that do not divide the dimension (e.g. seq-parallel
-    # specs against a decode step's length-1 sequence axis)
-    mesh = pol["mesh"]
-    fixed = []
-    for i, ax in enumerate(spec):
-        if ax is None:
-            fixed.append(None)
-            continue
-        axes = ax if isinstance(ax, tuple) else (ax,)
-        n = 1
-        for a in axes:
-            n *= mesh.shape[a]
-        fixed.append(ax if x.shape[i] % n == 0 else None)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*fixed)))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(pol["mesh"], spec))
 
 
-def current_policy() -> Optional[dict]:
-    return _POLICY.get()
+def shards(name: str, shape) -> bool:
+    """Whether ``constrain(x, name)`` splits an ``x`` of ``shape`` over more
+    than one device under the current policy."""
+    pol = _POLICY.get()
+    spec = None if pol is None else _fitted_spec(pol, name, shape)
+    return spec is not None and any(
+        ax is not None and _axis_size(pol["mesh"], ax) > 1 for ax in spec)

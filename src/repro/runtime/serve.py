@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..models import attention
 from ..models import transformer as tf
 from .pspec import activation_policy
 from .sharding import ShardingPolicy
@@ -84,7 +85,9 @@ class ServingEngine:
     dispatched; its child ``engine.writeback`` slices the slot's cache lane
     back in) and ``engine.sample`` (the next token read back to the host,
     where the host waits for the device). All but ``engine.step`` carry the
-    request's id as ``rid``.
+    request's id as ``rid``; ``engine.decode_call`` also carries ``kv_rows``,
+    the cache rows its full-length attention layers read
+    (``attention.decode_rows``).
     """
 
     def __init__(self, cfg: ArchConfig, params, *, batch_slots: int = 4,
@@ -129,7 +132,8 @@ class ServingEngine:
     def _step_slot(self, i: int, token: int) -> jax.Array:
         """Feed ``token`` to slot ``i`` at its position; returns that slot's
         next-token logits (vocab,)."""
-        with jax.profiler.TraceAnnotation("engine.decode_call", rid=self.slots[i].rid):
+        with jax.profiler.TraceAnnotation("engine.decode_call", rid=self.slots[i].rid,
+                                          kv_rows=attention.decode_rows(self.pos[i], self.max_len)):
             batch = {"tokens": jnp.full((len(self.slots), 1), token, jnp.int32)}
             logits, caches = self._decode(
                 self.params, self.caches, batch, jnp.int32(self.pos[i])

@@ -1,11 +1,14 @@
 """Per-architecture smoke tests (reduced configs, CPU) + semantic
 consistency: one-token decode must reproduce full-sequence forward."""
+import math
+
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
 from repro.configs import all_configs, get_config
+from repro.models import attention as attn
 from repro.models import transformer as tf
 
 ARCHS = sorted(all_configs())
@@ -111,6 +114,79 @@ def test_decode_matches_forward(arch):
         np.testing.assert_allclose(
             np.asarray(dec_logits), np.asarray(full_logits), rtol=2e-3, atol=2e-3
         )
+
+
+def _full_length_decode_attention(p, cfg, x, layer_cache, pos, *, local):
+    """Decode attention as it was before the live-row read: K/V repeated
+    to every query head and all cache rows scored, rows past ``pos`` masked."""
+    from repro.kernels.flash_attention.ref import repeat_kv
+
+    b = x.shape[0]
+    H, G, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    cache_len = layer_cache["k"].shape[1]
+    positions = jnp.full((b, 1), pos, jnp.int32)
+    q, k, v = attn._project_qkv(p, cfg, x, positions)
+    slot = jnp.where(jnp.array(local), pos % cache_len, jnp.minimum(pos, cache_len - 1))
+    ck = jax.lax.dynamic_update_slice(layer_cache["k"], k, (0, slot, 0, 0))
+    cv = jax.lax.dynamic_update_slice(layer_cache["v"], v, (0, slot, 0, 0))
+    kr = repeat_kv(ck, H // G)
+    vr = repeat_kv(cv, H // G)
+    scores = jnp.einsum("buhq,bthq->bhut", q, kr, preferred_element_type=jnp.float32)
+    scores *= 1.0 / math.sqrt(hd)
+    valid = jnp.arange(cache_len)[None, :] <= jnp.minimum(pos, cache_len - 1)
+    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
+    out = jnp.einsum("bhut,bthq->buhq", probs, vr)
+    return jnp.einsum("bshq,hqd->bsd", out, p["wo"]), {"k": ck, "v": cv}
+
+
+# (config, cache slots, ring buffer, positions decoded)
+DECODE_ROW_CASES = {
+    "dense-512": ("qwen3-0.6b", 512, False, (0, 126, 127, 128, 255, 256, 511, 600)),
+    "dense-200": ("qwen3-0.6b", 200, False, (0, 127, 128, 150, 199, 250)),
+    "ring-64": ("h2o-danube-3-4b", 64, True, (0, 63, 64, 200, 201)),
+    "ring-256": ("h2o-danube-3-4b", 256, True, (0, 127, 128, 255, 256, 300, 511)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_ROW_CASES))
+def test_decode_attention_reads_live_rows_exactly(case):
+    """Reading only the live blocks of the cache gives what scoring every
+    row gave, on full-length caches (one whose length is not a multiple of
+    the block among them) and on ring buffers before and past their wrap;
+    the rows past ``pos`` hold noise that only the mask hides."""
+    arch, cache_len, local, positions = DECODE_ROW_CASES[case]
+    cfg = get_config(arch).reduced()
+    p = attn.init_attn(jax.random.PRNGKey(0), cfg, jnp.float32)
+    kk, kv, kx = jax.random.split(jax.random.PRNGKey(1), 3)
+    b = 2
+    shape = (b, cache_len, cfg.num_kv_heads, cfg.resolved_head_dim)
+    cache = {"k": jax.random.normal(kk, shape), "v": jax.random.normal(kv, shape)}
+    x = jax.random.normal(kx, (b, 1, cfg.d_model))
+    new = jax.jit(lambda c, pos: attn.decode_attention(p, cfg, x, c, pos, local=local))
+    old = jax.jit(lambda c, pos: _full_length_decode_attention(p, cfg, x, c, pos, local=local))
+    for pos in positions:
+        y, c = new(cache, jnp.int32(pos))
+        y_ref, c_ref = old(cache, jnp.int32(pos))
+        err = float(jnp.abs(y - y_ref).max() / jnp.abs(y_ref).max())
+        assert err <= 1e-5, (pos, err)
+        np.testing.assert_array_equal(c["k"], c_ref["k"])
+        np.testing.assert_array_equal(c["v"], c_ref["v"])
+
+
+def test_decode_rows():
+    """Whole blocks of ``DECODE_BLOCK`` rows up to the last live slot, never
+    more than the cache holds."""
+    assert attn.DECODE_BLOCK == 128
+    assert [attn.decode_rows(p, 1024) for p in (0, 127, 128, 255, 256, 1023, 5000)] == [
+        128, 128, 256, 256, 384, 1024, 1024]
+    for cache_len in (32, 100, 128, 200, 512, 1024):
+        blk = min(128, cache_len)
+        for pos in range(2 * cache_len):
+            live = min(pos, cache_len - 1) + 1
+            rows = attn.decode_rows(pos, cache_len)
+            assert live <= rows <= cache_len, (cache_len, pos, rows)
+            assert rows == cache_len or (rows % blk == 0 and rows - live < blk)
 
 
 def test_vlm_prefix_embeds_change_output():

@@ -256,6 +256,46 @@ class TestServingEngine:
             assert req.generated[n] == int(jnp.argmax(logits[0, -1]))
 
 
+    def test_decode_calls_carry_kv_rows(self, tmp_path):
+        """A profiled drain puts on every ``engine.decode_call`` the cache
+        rows its decode reads: ``decode_rows`` of the slot's position."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        from repro.models.attention import decode_rows
+        from repro.runtime.serve import ServingEngine
+
+        cfg = get_config("qwen3-0.6b").reduced()
+        params = tf.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+        max_len = 256
+        eng = ServingEngine(cfg, params, batch_slots=2, max_len=max_len)
+        eng.submit(list(range(1, 131)), max_new_tokens=3)  # past 128 rows
+        eng.submit([5, 6, 7], max_new_tokens=2)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            done = eng.run_until_drained(max_ticks=50)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        calls: dict[int, list] = {}
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name == "engine.decode_call":
+                        stats = dict(ev.stats)
+                        calls.setdefault(int(stats["rid"]), []).append(
+                            (ev.start_ns, int(stats["kv_rows"])))
+        assert len(done) == 2
+        for req in done:  # its prompt, then every output token but the first
+            n = len(req.prompt) + req.max_new_tokens - 1
+            rows = [r for _, r in sorted(calls[req.rid])]
+            assert rows == [decode_rows(pos, max_len) for pos in range(n)]
+        assert {r for c in calls.values() for _, r in c} == {128, 256}
+
+
 # ------------------------------------------------------------------ pipeline --
 class TestPipelinePlanner:
     def test_plan_boundaries_cover_all_layers(self):

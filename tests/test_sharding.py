@@ -83,6 +83,55 @@ class TestPolicyRules:
         assert per_chip < 10 * 2**30, f"{arch}: {per_chip/2**30:.1f} GiB/chip"
 
 
+def _cache_reads(jaxpr, layer_shape):
+    """(while loops, slices of a layer's cache) in ``jaxpr`` and the jaxprs
+    it holds (the layer scan's body, a loop's body)."""
+    loops = slices = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            loops += 1
+        if (eqn.primitive.name in ("slice", "dynamic_slice")
+                and eqn.invars[0].aval.shape == layer_shape):
+            slices += 1
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(sub, "jaxpr", sub)  # a ClosedJaxpr's Jaxpr
+                if hasattr(inner, "eqns"):
+                    w, s = _cache_reads(inner, layer_shape)
+                    loops, slices = loops + w, slices + s
+    return loops, slices
+
+
+def test_decode_scores_policy_keeps_full_cache_read():
+    """Under a policy that shards the decode scores over the cache length
+    the decode step reads the whole cache (no loop, no block slice);
+    without one, or where the spec splits nothing (one device), it loops
+    over the live blocks."""
+    import jax.numpy as jnp
+    from jax.sharding import AbstractMesh
+
+    from repro.launch import specs
+    from repro.runtime.serve import make_serve_step
+    from repro.runtime.sharding import make_policy
+
+    cfg = get_config("qwen3-0.6b").reduced()
+    b, max_len = 2, 512
+    args = (specs.params_specs(cfg), specs.cache_specs(cfg, b, max_len),
+            {"tokens": jax.ShapeDtypeStruct((b, 1), jnp.int32)},
+            jax.ShapeDtypeStruct((), jnp.int32))
+    layer = (b, max_len, cfg.num_kv_heads, cfg.resolved_head_dim)
+
+    def reads(policy=None):
+        return _cache_reads(jax.make_jaxpr(make_serve_step(cfg, policy))(*args).jaxpr, layer)
+
+    sharded = make_policy(cfg, AbstractMesh((1, 4), ("data", "model")))
+    assert sharded.activation_specs().get("decode_scores") is not None
+    assert reads(sharded) == (0, 0)
+    # one loop in the layer scan, slicing a block of K and one of V
+    assert reads() == (1, 2)
+    assert reads(make_policy(cfg, AbstractMesh((1, 1), ("data", "model")))) == (1, 2)
+
+
 SMALL_MESH_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
