@@ -3,14 +3,21 @@ submitted when due and the engine ticks (``step``) while it has work.
 
 Set-up makes the weights, builds the engine, warms it up on every slot and
 drives the traffic's lead-in, so that the window opens on an engine as
-loaded as it stays. Through lead-in and window the driver stamps, by the
-host clock, each request's due time, the start of the tick that admitted it,
-and the end of the tick that produced each of its tokens (the engine's
-``step`` returns only after it has read every new token back to the host).
-The window closes at the end of the last tick that started inside it.
+loaded as it stays. Through lead-in, window and drain the driver stamps, by
+the host clock, each request's due time, the start of the tick that
+admitted it, and the end of the tick that produced each of its tokens (the
+engine's ``step`` returns only after it has read every new token back to
+the host). The window closes at the end of the last tick that started
+inside it; no request is submitted after that, and the engine drains the
+open ones, for up to ``DRAIN_S``, stamping as in the window.
 
-Correct means: every request submitted is answered in full (the engine
-finishes the late ones after the close, for up to ``DRAIN_S``), each with
+The end-to-end metrics count one whole schedule block: the requests due in
+the window, every one of their tokens whenever it is read back, the drain
+included, and none of the lead-in's. Their throughput runs until the last
+of them finishes. Where the window's edges fall among the stamps moves none
+of them.
+
+Correct means: every request submitted is answered in full, each with
 exactly its number of tokens, all in the vocabulary; and on a sample of
 finished requests drawn from the seed (the longest among them) the plain
 reference, run once over each prompt with its served tokens, puts no served
@@ -61,10 +68,41 @@ class _Tracked:
         self.prompt_len = len(req.prompt)
 
 
+def _tick(engine, active, m, clock):
+    """One engine tick: stamps each new token with the tick's end, and a
+    request's admission with its start; returns the requests still open and
+    the tick's (start, end, flops, bytes, calls)."""
+    ts = time.perf_counter()
+    with clock.span("bench.step"):
+        engine.step()
+    te = time.perf_counter()
+    flops = nbytes = 0.0
+    calls = 0
+    still = []
+    for tr in active:
+        n = len(tr.req.generated)
+        new = n - len(tr.tokens)
+        if new:
+            if not tr.tokens:  # admitted this tick: prefilled at 0..P-1
+                tr.admitted = ts
+                positions = range(tr.prompt_len)
+            else:  # fed its previous token at P + k - 1
+                positions = [tr.prompt_len + len(tr.tokens) - 1]
+            for pos in positions:
+                f, b = work.decode_call(m, pos)
+                flops += f
+                nbytes += b
+                calls += 1
+            tr.tokens.extend([te] * new)
+        if not tr.req.done:
+            still.append(tr)
+    return still, (ts, te, flops, nbytes, calls)
+
+
 def window(cell, state, clock, drain_s=DRAIN_S):
     """Drive the lead-in, then the window of ``cell.seconds``; returns the
     host records. Finishes the requests still open at the close for up to
-    ``drain_s`` more (for the check only)."""
+    ``drain_s`` more, with ticks stamped as in the window."""
     engine, reqs = state["engine"], state["schedule"]
     m = cell.model
     t0 = time.perf_counter() + float(cell.traffic.get("lead_in_s", 0.0))
@@ -100,39 +138,15 @@ def window(cell, state, clock, drain_s=DRAIN_S):
             with clock.span("bench.wait"):
                 time.sleep(max(0.0, wake - time.perf_counter()))
             continue
-        ts = time.perf_counter()
-        with clock.span("bench.step"):
-            engine.step()
-        te = time.perf_counter()
-        flops = nbytes = 0.0
-        calls = 0
-        still = []
-        for tr in active:
-            n = len(tr.req.generated)
-            new = n - len(tr.tokens)
-            if new:
-                if not tr.tokens:  # admitted this tick: prefilled at 0..P-1
-                    tr.admitted = ts
-                    positions = range(tr.prompt_len)
-                else:  # fed its previous token at P + k - 1
-                    positions = [tr.prompt_len + len(tr.tokens) - 1]
-                for pos in positions:
-                    f, b = work.decode_call(m, pos)
-                    flops += f
-                    nbytes += b
-                    calls += 1
-                tr.tokens.extend([te] * new)
-            if not tr.req.done:
-                still.append(tr)
-        active = still
-        if ts >= t0:
-            ticks.append((ts, te, flops, nbytes, calls))
+        active, tick = _tick(engine, active, m, clock)
+        if tick[0] >= t0:
+            ticks.append(tick)
     if not opened:
         clock.start_window(t0)
     t_close = ticks[-1][1] if ticks and ticks[-1][1] > end else end
     clock.end_window(t_close)
-    # due while the last tick ran: unanswered in the window, counted at
-    # their age at its close
+    # due while the last tick ran: submitted at the close, and waited for
+    # since their due time
     for r in reqs[nxt:]:
         engine.submit(r.prompt, max_new_tokens=r.max_new_tokens)
         tr = _Tracked(engine.queue[-1], t0 + r.due_s)
@@ -141,17 +155,10 @@ def window(cell, state, clock, drain_s=DRAIN_S):
         tracked.append(tr)
     jax.block_until_ready(engine.caches)
     clock.stop_trace()
-    # answers that come after the close are late, not wrong: finish them
-    # (for the check only), up to drain_s past the close
+    # the drain: no arrivals, the open requests finished up to drain_s past
+    # the close; its stamps count for the requests due in the window
     while active and time.perf_counter() < t_close + drain_s:
-        engine.step()
-        te = time.perf_counter()
-        for tr in active:
-            new = len(tr.req.generated) - len(tr.tokens)
-            if new and not tr.tokens:
-                tr.admitted = te
-            tr.tokens.extend([te] * new)
-        active = [tr for tr in active if not tr.req.done]
+        active, _ = _tick(engine, active, m, clock)
     offered = sum(r.max_new_tokens for r in reqs if r.due_s >= 0) / cell.seconds
     return {"t0": t0, "t_close": t_close, "ticks": ticks, "requests": tracked,
             "lateness": lateness, "scheduled": len(reqs), "offered_output_per_s": offered}
@@ -161,23 +168,34 @@ def _in_window(rec, t) -> bool:
     return rec["t0"] < t <= rec["t_close"]
 
 
-def end_to_end(cell, rec) -> dict:
-    """Rates and tails of the window: output tokens of every request stamped
-    in it, the gaps between them, and the first-token wait of every request
-    due in it."""
-    t0, t_close = rec["t0"], rec["t_close"]
-    ttft, gaps, out_tokens = [], [], 0
-    for tr in rec["requests"]:
-        stamps = [t for t in tr.tokens if _in_window(rec, t)]
-        out_tokens += len(stamps)
-        gaps.extend(b - a for a, b in zip(stamps, stamps[1:]))
-        if tr.due >= t0:
-            first = tr.tokens[0] if tr.tokens and tr.tokens[0] <= t_close else t_close
-            ttft.append(first - tr.due)
+def _block(rec) -> dict:
+    """The measured set, one whole schedule block: the requests due in the
+    window, each with every token it was read back, whenever (the drain
+    included). ``t_done`` is the last of their stamps, None while one of
+    them is unanswered."""
+    block = [tr for tr in rec["requests"] if tr.due >= rec["t0"]]
+    whole = bool(block) and all(tr.req.done for tr in block)
     return {
-        "output_tokens_per_s": out_tokens / (t_close - t0),
-        "ttft_p95_ms": 1e3 * float(np.percentile(ttft, 95)) if ttft else None,
-        "itl_p95_ms": 1e3 * float(np.percentile(gaps, 95)) if gaps else None,
+        "tokens": sum(len(tr.tokens) for tr in block),
+        "gaps": [b - a for tr in block for a, b in zip(tr.tokens, tr.tokens[1:])],
+        "ttft": [tr.tokens[0] - tr.due for tr in block if tr.tokens],
+        "t_done": max(tr.tokens[-1] for tr in block) if whole else None,
+    }
+
+
+def end_to_end(cell, rec) -> dict:
+    """Rates and tails of one whole schedule block: its output tokens over
+    the time from the window's open until the last of them is read back,
+    every gap between consecutive tokens of a request, and every request's
+    first-token wait from its due time. Nothing while one of them is
+    unanswered (the run is then not correct)."""
+    b = _block(rec)
+    if b["t_done"] is None:
+        return dict.fromkeys(("output_tokens_per_s", "ttft_p95_ms", "itl_p95_ms"))
+    return {
+        "output_tokens_per_s": b["tokens"] / (b["t_done"] - rec["t0"]),
+        "ttft_p95_ms": 1e3 * float(np.percentile(b["ttft"], 95)),
+        "itl_p95_ms": 1e3 * float(np.percentile(b["gaps"], 95)) if b["gaps"] else None,
     }
 
 
@@ -185,6 +203,7 @@ def summary(rec) -> dict:
     reqs, late = rec["requests"], rec["lateness"]
     t0, t_close = rec["t0"], rec["t_close"]
     due = [tr for tr in reqs if tr.due >= t0]
+    b = _block(rec)
     return {
         "scheduled": rec["scheduled"], "lead_in": len(reqs) - len(due), "due": len(due),
         "open_at_start": sum(1 for tr in reqs if tr.due < t0 and not (
@@ -196,6 +215,9 @@ def summary(rec) -> dict:
         "unanswered": sum(1 for tr in reqs if not tr.req.done),
         "output_tokens_in_window": sum(1 for tr in reqs for t in tr.tokens
                                        if _in_window(rec, t)),
+        "output_tokens_counted": b["tokens"], "gaps_counted": len(b["gaps"]),
+        "ttft_counted": len(b["ttft"]),
+        "block_done_s": None if b["t_done"] is None else b["t_done"] - t0,
         "offered_output_tokens_per_s": rec["offered_output_per_s"],
         "ticks": len(rec["ticks"]),
         "submit_late_ms_p50": 1e3 * float(np.median(late)) if late else 0.0,
